@@ -18,8 +18,11 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
+
+from repro.compile_cache import use_compile_cache
 
 from . import (allpairs_throughput, common, construction_throughput,
                degraded_serving, fig3_synthetic_ip, fig4_binary,
@@ -111,6 +114,8 @@ def main() -> None:
                          "registry-snapshot row per module to the JSON "
                          "artifact (DESIGN.md §19)")
     args = ap.parse_args()
+    use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     common.set_repeats(args.repeats)
     common.set_roofline(args.roofline)
     common.set_obs(args.obs)

@@ -27,7 +27,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.priority import priority_sketch
@@ -124,10 +123,10 @@ def make_sketchdp_grad_fn(mesh: Mesh, loss_fn: Callable, m: int, *,
     def grad_fn(params, batch, ef_state, step):
         pspec = jax.tree.map(lambda _: P(), params)
         bspec = jax.tree.map(lambda _: P(axes), batch)
-        fn = shard_map(local_grads, mesh=mesh,
-                       in_specs=(pspec, bspec, P(axes), P()),
-                       out_specs=(P(), pspec, P(axes)),
-                       check_rep=False)
+        fn = jax.shard_map(local_grads, mesh=mesh,
+                           in_specs=(pspec, bspec, P(axes), P()),
+                           out_specs=(P(), pspec, P(axes)),
+                           check_vma=False)
         return fn(params, batch, ef_state, step)
 
     return grad_fn

@@ -26,7 +26,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.merge import (PartitionStats, merge_sketches_many,
                               partition_stats)
@@ -206,6 +205,6 @@ def partitioned_sketch_corpus_sharded(A: jnp.ndarray, m: int, seed, *,
                                    adaptive=adaptive, stats=gst,
                                    dedupe=False)
 
-    fn = shard_map(local, mesh=mesh, in_specs=P(None, axis_name),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=P(None, axis_name),
+                       out_specs=P(), check_vma=False)
     return fn(A)

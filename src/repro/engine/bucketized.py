@@ -31,12 +31,9 @@ import jax.numpy as jnp
 
 from repro.core.hashing import hash_unit
 from repro.core.sketches import INVALID_IDX, sampling_ranks
+from repro.kernels.dispatch import interpret, resolve_use_pallas
 
 from .containers import BucketizedPayloads, PayloadSketch, payload_weight
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("n_buckets", "slots"))
@@ -98,7 +95,6 @@ def bucketized_products(A: BucketizedPayloads, B: BucketizedPayloads, *,
     from repro.kernels.matrix_sketch.matrix_sketch import \
         matrix_products_pallas
     from repro.kernels.matrix_sketch.ref import matrix_products_ref
-    from repro.kernels.sketch_build.ops import resolve_use_pallas
     if A.idx.shape != B.idx.shape:
         raise ValueError(f"batch layouts differ: {A.idx.shape} vs "
                          f"{B.idx.shape}")
@@ -107,7 +103,7 @@ def bucketized_products(A: BucketizedPayloads, B: BucketizedPayloads, *,
     if resolve_use_pallas(use_pallas):
         return matrix_products_pallas(A.idx, A.payload, a_p,
                                       B.idx, B.payload, b_p,
-                                      interpret=_use_interpret())
+                                      interpret=interpret())
     return matrix_products_ref(A.idx, A.payload, a_p, B.idx, B.payload, b_p)
 
 
@@ -116,13 +112,16 @@ def bucketized_products(A: BucketizedPayloads, B: BucketizedPayloads, *,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("m", "variant"))
+@functools.partial(jax.jit, static_argnames=("m", "variant", "use_pallas"))
 def merged_tau_bucketized_payloads(A: BucketizedPayloads,
                                    B: BucketizedPayloads, seed, *, m: int,
-                                   variant: str = "l2") -> jnp.ndarray:
+                                   variant: str = "l2",
+                                   use_pallas: bool | None = None
+                                   ) -> jnp.ndarray:
     """Per-row merged priority tau: the (m+1)-st smallest rank of the union
     candidates (kept ranks of both sides, b-duplicates masked, plus both
-    published taus — DESIGN.md §14, payload-generic weights)."""
+    published taus — DESIGN.md §14, payload-generic weights).
+    ``use_pallas`` picks the selection kernels (``kth_smallest_ranks``)."""
     from repro.kernels.sketch_build.ops import kth_smallest_ranks
     D, Bk, S = A.idx.shape
 
@@ -142,7 +141,7 @@ def merged_tau_bucketized_payloads(A: BucketizedPayloads,
     cand = jnp.concatenate(
         [ra.reshape(D, -1), rb.reshape(D, -1),
          jnp.reshape(A.tau, (D, 1)), jnp.reshape(B.tau, (D, 1))], axis=1)
-    return kth_smallest_ranks(cand, m + 1)
+    return kth_smallest_ranks(cand, m + 1, use_pallas=use_pallas)
 
 
 @functools.partial(jax.jit, static_argnames=("variant",))
@@ -210,7 +209,8 @@ def merge_bucketized_payloads(A: BucketizedPayloads, B: BucketizedPayloads,
         return BucketizedPayloads(out.idx, out.val[..., None], out.tau,
                                   out.dropped)
     if tau is None:
-        tau = merged_tau_bucketized_payloads(A, B, seed, m=m, variant=variant)
+        tau = merged_tau_bucketized_payloads(A, B, seed, m=m, variant=variant,
+                                             use_pallas=use_pallas)
     out_idx, out_pay, new_drop = _merge_payloads_oracle(
         A.idx, A.payload, B.idx, B.payload, tau, seed, variant=variant)
     return BucketizedPayloads(out_idx, out_pay,

@@ -39,10 +39,10 @@ from repro import obs
 from repro.core.hashing import hash_unit
 from repro.core.sketches import INVALID_IDX, sampling_ranks
 from repro.core.threshold import adaptive_tau
+from repro.kernels.dispatch import resolve_use_pallas
 from repro.kernels.sketch_build.ops import (_front_end, _overflow_cut,
                                             adaptive_tau_batched,
-                                            kth_smallest_ranks,
-                                            resolve_use_pallas)
+                                            kth_smallest_ranks)
 
 from .containers import PayloadSketch, payload_capacity, payload_weight
 
@@ -51,7 +51,7 @@ SELECTORS = ("pallas", "xla", "sort")
 
 def resolve_selector(selector: str | None) -> str:
     """None -> auto: Pallas selection on TPU, the XLA formulation elsewhere
-    (mirrors ``kernels.sketch_build.resolve_use_pallas``)."""
+    (``kernels.dispatch.resolve_use_pallas``)."""
     if selector is None:
         return "pallas" if resolve_use_pallas(None) else "xla"
     if selector not in SELECTORS:

@@ -1,8 +1,9 @@
 """Pallas TPU kernels for the paper's compute hot spots.
 
 Each kernel ships three files: ``<name>.py`` (pl.pallas_call + BlockSpec
-VMEM tiling), ``ops.py`` (jit'd public wrapper, interpret=True off-TPU) and
-``ref.py`` (pure-jnp oracle the tests assert against):
+VMEM tiling), ``ops.py`` (jit'd public wrapper) and ``ref.py`` (pure-jnp
+oracle the tests assert against).  ``dispatch.py`` holds the one rule for
+where a kernel runs: compiled by Mosaic on a TPU, interpret mode elsewhere.
 
 - ``hash_rank``          fused hash + sampling rank (the O(N) loop of Algs 1/3)
 - ``sketch_build``       batched linear-time sketch construction: fused 2D
@@ -10,9 +11,10 @@ VMEM tiling), ``ops.py`` (jit'd public wrapper, interpret=True off-TPU) and
   compaction — replaces the O(n log n) sort/top_k build path (DESIGN.md §13)
 - ``countsketch``        CountSketch as one-hot MXU matmuls (scatter-free)
 - ``jl_rademacher``      matrix-free JL projection (Pi regenerated in VMEM)
-- ``intersect_estimate`` bucketized batched estimator: one query vs a corpus
-  (serving path) and the tiled all-pairs / co-moments kernel that emits the
-  full (D1, D2) estimate matrix in one launch (the O(D^2 m) workload)
+- ``intersect_estimate`` bucketized batched estimator: the tiled all-pairs /
+  co-moments kernel that emits the full (D1, D2) estimate matrix in one
+  launch (the O(D^2 m) workload); one query row against a corpus is the
+  serving path
 - ``sketch_merge``       batched merge of two bucketized corpora: per-bucket
   union + dedupe + rank re-cut in one launch for all D rows — the serving
   half of the partition-merge subsystem (DESIGN.md §14)

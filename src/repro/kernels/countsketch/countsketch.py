@@ -62,7 +62,7 @@ def _kernel(seeds_ref, val_ref, out_ref, *, m: int):
 
 
 def countsketch_pallas(values: jnp.ndarray, seeds: jnp.ndarray, m_pad: int,
-                       *, m: int, interpret: bool = True) -> jnp.ndarray:
+                       *, m: int, interpret: bool) -> jnp.ndarray:
     """values: (n,) f32 with n % L == 0; m_pad % M_TILE == 0.
     Returns (m_pad,) bucket array (only the first ``m`` buckets are live)."""
     n = values.shape[0]

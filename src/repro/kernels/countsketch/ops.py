@@ -6,12 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..dispatch import interpret
 from .countsketch import L, M_TILE, countsketch_pallas
 from .ref import countsketch_ref
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("m", "use_pallas"))
@@ -25,5 +22,5 @@ def countsketch(values: jnp.ndarray, m: int, seed_bucket, seed_sign, *,
     m_pad = -(-m // M_TILE) * M_TILE
     seeds = jnp.stack([jnp.asarray(seed_bucket, jnp.int32),
                        jnp.asarray(seed_sign, jnp.int32)])
-    out = countsketch_pallas(v, seeds, m_pad, m=m, interpret=_use_interpret())
+    out = countsketch_pallas(v, seeds, m_pad, m=m, interpret=interpret())
     return out[:m]

@@ -7,9 +7,9 @@ we fuse (a) the integer hash of the global coordinate, (b) the weight
 the vector is read from HBM exactly once and nothing is materialized in
 between — the CPU implementation's hash-then-filter does three passes.
 
-Layout: the vector is viewed as (rows, 128) with (8, 128)-aligned tiles
-(VPU lane shape); the global coordinate is reconstructed from the grid
-position, so no index array is ever stored.
+Layout: the vector is viewed as (rows, 128) lanes, processed in tiles of up
+to ``MAX_ROWS`` rows per grid step; the global coordinate is reconstructed
+from the grid position, so no index array is ever stored.
 """
 from __future__ import annotations
 
@@ -19,15 +19,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 SUBLANES = 8
 LANES = 128
-BLOCK = SUBLANES * LANES  # elements per grid step
+BLOCK = SUBLANES * LANES  # padding granule: vectors pad to whole (8, 128) tiles
+MAX_ROWS = 256            # rows of 128 lanes per grid step (32K elements)
 
 _GOLDEN = np.uint32(0x9E3779B9)
 _M1 = np.uint32(0x21F0AAAD)
 _M2 = np.uint32(0x735A2D97)
 _UNIT = np.float32(1.0 / (1 << 24))
+
+
+def row_tile(rows: int) -> int:
+    """Rows per grid step: the largest power of two <= MAX_ROWS dividing
+    ``rows`` (a multiple of SUBLANES, so the tile is at least one vreg)."""
+    assert rows % SUBLANES == 0, rows
+    t = MAX_ROWS
+    while rows % t:
+        t //= 2
+    return t
 
 
 def _mix32(x):
@@ -50,19 +62,27 @@ def _weight(v, variant: str):
 
 
 def _block_hash_rank(seed_ref, v, block_j, variant: str):
-    """Shared fused body: (h, rank) for one (SUBLANES, LANES) value block at
-    block index ``block_j`` along the vector.  The single source of the
+    """Shared fused body: (h, rank) for one (R, LANES) value block at block
+    index ``block_j`` along the vector.  The single source of the
     hash/rank formula for every kernel that must stay bit-coordinated
     (scalar, batched, and sketch_build's histogram-fused variant)."""
-    r = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
-    c = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1)
-    gidx = ((block_j * SUBLANES + r) * LANES + c).astype(jnp.uint32)
+    rows = v.shape[0]
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+    gidx = ((block_j * rows + r) * LANES + c).astype(jnp.uint32)
     seed = seed_ref[0, 0].astype(jnp.uint32)
     h = _mix32(gidx * _GOLDEN + seed)
-    hu = ((h >> np.uint32(8)).astype(jnp.float32) + np.float32(0.5)) * _UNIT
+    # h >> 8 < 2^24: the int32 hop is exact (Mosaic has no u32 -> f32 cast)
+    top = (h >> np.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+    hu = (top + np.float32(0.5)) * _UNIT
     w = _weight(v.astype(jnp.float32), variant)
     rank = jnp.where(w > 0, hu / jnp.where(w > 0, w, 1.0), jnp.inf)
     return hu, rank
+
+
+def seed_spec():
+    """The (1, 1) int32 seed lives in SMEM: a scalar read, no vector load."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _kernel(seed_ref, val_ref, h_ref, rank_ref, *, variant: str):
@@ -73,21 +93,21 @@ def _kernel(seed_ref, val_ref, h_ref, rank_ref, *, variant: str):
 
 
 def hash_rank_pallas(values2d: jnp.ndarray, seed: jnp.ndarray, *,
-                     variant: str = "l2", interpret: bool = True):
+                     variant: str = "l2", interpret: bool):
     """values2d: (rows, 128) f32, rows % 8 == 0.  Returns (h, rank), same shape."""
     rows = values2d.shape[0]
-    assert values2d.shape[1] == LANES and rows % SUBLANES == 0
-    grid = (rows // SUBLANES,)
+    assert values2d.shape[1] == LANES
+    rt = row_tile(rows)
     kern = functools.partial(_kernel, variant=variant)
     h, rank = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
                    jax.ShapeDtypeStruct((rows, LANES), jnp.float32)),
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                  pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))],
-        out_specs=(pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
-                   pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))),
+        grid=(rows // rt,),
+        in_specs=[seed_spec(),
+                  pl.BlockSpec((rt, LANES), lambda i: (i, 0))],
+        out_specs=(pl.BlockSpec((rt, LANES), lambda i: (i, 0)),
+                   pl.BlockSpec((rt, LANES), lambda i: (i, 0))),
         interpret=interpret,
     )(seed.reshape(1, 1).astype(jnp.int32), values2d)
     return h, rank
@@ -99,7 +119,7 @@ def _batched_kernel(seed_ref, val_ref, h_ref, rank_ref, *, variant: str):
     The global coordinate is the position *within the row* (all vectors of a
     coordinated corpus share the hash stream), reconstructed from the block
     grid position j — no index array is materialized.  The hash output is a
-    single (blocks, BLOCK) row shared by every d (its block is revisited once
+    single (rows, 128) array shared by every d (its block is revisited once
     per vector; every visit writes the same bits, so the revisit is benign).
     """
     hu, rank = _block_hash_rank(seed_ref, val_ref[0], pl.program_id(1),
@@ -109,7 +129,7 @@ def _batched_kernel(seed_ref, val_ref, h_ref, rank_ref, *, variant: str):
 
 
 def hash_rank_batched_pallas(values3d: jnp.ndarray, seed: jnp.ndarray, *,
-                             variant: str = "l2", interpret: bool = True):
+                             variant: str = "l2", interpret: bool):
     """Batched fused pass: values3d (D, rows, 128) f32, rows % 8 == 0.
 
     Returns (h (rows, 128), rank (D, rows, 128)): hash + weight + rank for a
@@ -117,18 +137,18 @@ def hash_rank_batched_pallas(values3d: jnp.ndarray, seed: jnp.ndarray, *,
     ``hash_rank_pallas`` that feeds the sketch_build pipeline.
     """
     D, rows, lanes = values3d.shape
-    assert lanes == LANES and rows % SUBLANES == 0
-    grid = (D, rows // SUBLANES)
+    assert lanes == LANES
+    rt = row_tile(rows)
     kern = functools.partial(_batched_kernel, variant=variant)
     h, rank = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
                    jax.ShapeDtypeStruct((D, rows, LANES), jnp.float32)),
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, 1), lambda d, j: (0, 0)),
-                  pl.BlockSpec((1, SUBLANES, LANES), lambda d, j: (d, j, 0))],
-        out_specs=(pl.BlockSpec((SUBLANES, LANES), lambda d, j: (j, 0)),
-                   pl.BlockSpec((1, SUBLANES, LANES), lambda d, j: (d, j, 0))),
+        grid=(D, rows // rt),
+        in_specs=[seed_spec(),
+                  pl.BlockSpec((1, rt, LANES), lambda d, j: (d, j, 0))],
+        out_specs=(pl.BlockSpec((rt, LANES), lambda d, j: (j, 0)),
+                   pl.BlockSpec((1, rt, LANES), lambda d, j: (d, j, 0))),
         interpret=interpret,
     )(seed.reshape(1, 1).astype(jnp.int32), values3d)
     return h, rank

@@ -1,5 +1,5 @@
 """Jit'd public wrapper for the hash_rank kernel: pad/reshape to the TPU
-layout, dispatch to the Pallas kernel (interpret=True off-TPU), unpad."""
+layout, dispatch to the Pallas kernel (``kernels.dispatch``), unpad."""
 from __future__ import annotations
 
 import functools
@@ -7,13 +7,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..dispatch import interpret
 from .hash_rank import (BLOCK, LANES, hash_rank_batched_pallas,
                         hash_rank_pallas)
 from .ref import hash_rank_batched_ref, hash_rank_ref
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("variant", "use_pallas"))
@@ -28,7 +25,7 @@ def hash_rank(values: jnp.ndarray, seed, *, variant: str = "l2",
     v2 = v.reshape(n_pad // LANES, LANES)
     seed_arr = jnp.asarray(seed, jnp.int32)
     h, rank = hash_rank_pallas(v2, seed_arr, variant=variant,
-                               interpret=_use_interpret())
+                               interpret=interpret())
     return h.reshape(-1)[:n], rank.reshape(-1)[:n]
 
 
@@ -49,5 +46,5 @@ def hash_rank_batched(values: jnp.ndarray, seed, *, variant: str = "l2",
     v3 = v.reshape(D, n_pad // LANES, LANES)
     seed_arr = jnp.asarray(seed, jnp.int32)
     h, rank = hash_rank_batched_pallas(v3, seed_arr, variant=variant,
-                                       interpret=_use_interpret())
+                                       interpret=interpret())
     return h.reshape(-1)[:n], rank.reshape(D, -1)[:, :n]

@@ -25,8 +25,8 @@ from repro import obs
 from repro.core.hashing import hash_bucket
 from repro.core.sketches import INVALID_IDX, Sketch
 
-from .intersect_estimate import (CT, QT, allpairs_estimate_pallas,
-                                 intersect_estimate_pallas)
+from ..dispatch import interpret
+from .intersect_estimate import CT, QT, allpairs_estimate_pallas
 from .ref import allpairs_estimate_ref, intersect_estimate_ref
 
 DEFAULT_BUCKET_SEED = 0xB0C4
@@ -97,10 +97,6 @@ def bucketize_corpus(sketches: Sketch, **kw) -> BucketizedSketch:
         sketches.idx, sketches.val, sketches.tau)
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def slot_inclusion_probs(bc: BucketizedSketch, *, variant: str = "l2") -> jnp.ndarray:
     """Per-slot inclusion probability min(1, tau * w(val)) for a (C, B, S)
     bucketized corpus; 1.0 at padding slots (w == 0) so inf taus from the
@@ -115,7 +111,8 @@ def slot_inclusion_probs(bc: BucketizedSketch, *, variant: str = "l2") -> jnp.nd
 
 def query_corpus(q: BucketizedSketch, corpus: BucketizedSketch, *,
                  use_pallas: bool = True) -> jnp.ndarray:
-    """(C,) inner product estimates of one query against a corpus."""
+    """(C,) inner product estimates of one query against a corpus: the
+    all-pairs kernel with a single query row (one launch over the corpus)."""
     if obs.enabled() and not isinstance(q.idx, jax.core.Tracer):
         obs.kernel_launch("intersect_estimate.query")
     return _query_corpus_jit(q, corpus, use_pallas=use_pallas)
@@ -127,16 +124,12 @@ def _query_corpus_jit(q: BucketizedSketch, corpus: BucketizedSketch, *,
     if not use_pallas:
         return intersect_estimate_ref(q.idx, q.val, q.tau,
                                       corpus.idx, corpus.val, corpus.tau)
-    C = corpus.idx.shape[0]
-    C_pad = -(-C // CT) * CT
-    pad = C_pad - C
-    ci = jnp.pad(corpus.idx, ((0, pad), (0, 0), (0, 0)),
-                 constant_values=INVALID_IDX)
-    cv = jnp.pad(corpus.val, ((0, pad), (0, 0), (0, 0)))
-    ct = jnp.pad(corpus.tau, (0, pad), constant_values=1.0)
-    out = intersect_estimate_pallas(q.idx, q.val, q.tau, ci, cv, ct,
-                                    interpret=_use_interpret())
-    return out[:C]
+    q1 = BucketizedSketch(q.idx[None], q.val[None],
+                          jnp.reshape(q.tau, (1,)), q.dropped)
+    return _allpairs_tiled(q1.idx, q1.val, slot_inclusion_probs(q1),
+                           corpus.idx, corpus.val,
+                           slot_inclusion_probs(corpus),
+                           moments=False, qt=QT, ct=CT)[0]
 
 
 def _pad_rows(idx, val, p, tile: int):
@@ -164,11 +157,28 @@ def _allpairs_dispatch(a_idx, a_val, a_p, b_idx, b_val, b_p, *,
         out = allpairs_estimate_ref(a_idx, a_val, a_p, b_idx, b_val, b_p,
                                     moments=moments, ct=ref_chunk)
         return out[:D1, :D2]
+    return _allpairs_tiled(a_idx, a_val, a_p, b_idx, b_val, b_p,
+                           moments=moments, qt=qt, ct=ct)
+
+
+def _tile(rows: int, tile: int, align: int) -> int:
+    """Block rows for one side: ``tile`` rows when the side is longer, else
+    the whole side rounded up to ``align``; the TPU block rule wants a
+    multiple of 8 sublanes / 128 lanes or the whole (padded) dim."""
+    return tile if rows > tile else -(-rows // align) * align
+
+
+def _allpairs_tiled(a_idx, a_val, a_p, b_idx, b_val, b_p, *, moments: bool,
+                    qt: int, ct: int):
+    """Pad both sides to whole tiles, run the all-pairs kernel, unpad.  A
+    single-row A side (the query path) keeps one-row blocks."""
+    D1, D2 = a_idx.shape[0], b_idx.shape[0]
+    qt = _tile(D1, qt, 1 if D1 == 1 else 8)
+    ct = _tile(D2, ct, 8)
     ai, av, ap = _pad_rows(a_idx, a_val, a_p, qt)
     bi, bv, bp = _pad_rows(b_idx, b_val, b_p, ct)
     out = allpairs_estimate_pallas(ai, av, ap, bi, bv, bp, qt=qt, ct=ct,
-                                   moments=moments,
-                                   interpret=_use_interpret())
+                                   moments=moments, interpret=interpret())
     return out[:D1, :D2]
 
 
@@ -219,12 +229,10 @@ def _estimate_tile_rows_jit(a_idx, a_val, a_p, b_idx, b_val, b_p,
     gather = lambda arr, rows: jnp.take(arr, rows, axis=0, mode="clip")
     ai, av, ap = (gather(x, rows_a) for x in (a_idx, a_val, a_p))
     bi, bv, bp = (gather(x, rows_b) for x in (b_idx, b_val, b_p))
-    tq, tc = rows_a.shape[0], rows_b.shape[0]
     if not use_pallas:
         return allpairs_estimate_ref(ai, av, ap, bi, bv, bp)
-    return allpairs_estimate_pallas(ai, av, ap, bi, bv, bp,
-                                    qt=min(QT, tq), ct=min(CT, tc),
-                                    interpret=_use_interpret())
+    return _allpairs_tiled(ai, av, ap, bi, bv, bp, moments=False,
+                           qt=QT, ct=CT)
 
 
 def allpairs_moments(a_idx, a_val, a_p, b_idx, b_val, b_p, *, qt: int = QT,

@@ -57,7 +57,7 @@ def _kernel(seed_ref, val_ref, out_ref):
 
 
 def jl_pallas(values: jnp.ndarray, seed: jnp.ndarray, m_pad: int, *,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: bool) -> jnp.ndarray:
     n = values.shape[0]
     assert n % N_TILE == 0 and m_pad % M_TILE == 0
     grid = (m_pad // M_TILE, n // N_TILE)

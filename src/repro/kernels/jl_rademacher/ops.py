@@ -6,12 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..dispatch import interpret
 from .jl_rademacher import M_TILE, N_TILE, jl_pallas
 from .ref import jl_ref
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("m", "use_pallas"))
@@ -24,5 +21,5 @@ def jl_project(values: jnp.ndarray, m: int, seed, *, use_pallas: bool = True) ->
     v = jnp.pad(values.astype(jnp.float32), (0, n_pad - n))
     m_pad = -(-m // M_TILE) * M_TILE
     out = jl_pallas(v, jnp.asarray(seed, jnp.int32), m_pad,
-                    interpret=_use_interpret())
+                    interpret=interpret())
     return out[:m] / jnp.sqrt(jnp.float32(m))

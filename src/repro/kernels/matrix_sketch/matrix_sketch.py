@@ -68,7 +68,7 @@ def _kernel(ai_ref, ar_ref, ap_ref, bi_ref, br_ref, bp_ref, out_ref, *,
 
 
 def matrix_products_pallas(a_idx, a_rows, a_p, b_idx, b_rows, b_p, *,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool) -> jnp.ndarray:
     """Batched fused estimator: (P, B, S) ids + (P, B, S, d) rows + (P, B, S)
     per-slot inclusion probabilities (1.0 at padding) per side -> the
     (P, d_a, d_b) estimate batch in one launch (grid over P)."""

@@ -1,8 +1,7 @@
 from .combined import (build_combined_priority_corpus,
                        build_combined_threshold_corpus)
 from .ops import (adaptive_tau_batched, build_priority_corpus,
-                  build_threshold_corpus, kth_smallest_ranks, pack_kept,
-                  resolve_use_pallas)
+                  build_threshold_corpus, kth_smallest_ranks, pack_kept)
 from .ref import (build_combined_priority_corpus_ref,
                   build_combined_threshold_corpus_ref,
                   build_priority_corpus_ref, build_threshold_corpus_ref)
@@ -13,6 +12,6 @@ __all__ = [
     "build_combined_priority_corpus", "build_combined_threshold_corpus",
     "build_priority_corpus_ref", "build_threshold_corpus_ref",
     "build_combined_priority_corpus_ref", "build_combined_threshold_corpus_ref",
-    "kth_smallest_ranks", "pack_kept", "resolve_use_pallas",
+    "kth_smallest_ranks", "pack_kept",
     "NBINS", "hash_rank_hist_pallas", "rank_hist_pallas",
 ]

@@ -21,6 +21,7 @@ from repro.core.hashing import hash_unit
 from repro.core.join_correlation import CombinedSketch
 from repro.core.sketches import default_capacity
 
+from ..dispatch import resolve_use_pallas
 from .ops import _overflow_cut, kth_smallest_ranks, pack_kept
 
 
@@ -90,7 +91,6 @@ def build_combined_priority_corpus(A: jnp.ndarray, m: int, seed, *,
                                    use_pallas: bool | None = None
                                    ) -> CombinedSketch:
     """Batched linear-time Algorithm 6 over (D, n) (see module docstring)."""
-    from .ops import resolve_use_pallas
     A = jnp.atleast_2d(jnp.asarray(A, jnp.float32))
     return _build_combined_priority(
         A, seed, m=m, use_pallas=resolve_use_pallas(use_pallas))
@@ -139,7 +139,6 @@ def build_combined_threshold_corpus(A: jnp.ndarray, m: int, seed, *,
                                     use_pallas: bool | None = None
                                     ) -> CombinedSketch:
     """Batched Algorithm 5 (adaptive m' bisection + linear compaction)."""
-    from .ops import resolve_use_pallas
     A = jnp.atleast_2d(jnp.asarray(A, jnp.float32))
     if cap is None:
         cap = default_capacity(m)

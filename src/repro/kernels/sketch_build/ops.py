@@ -33,26 +33,10 @@ from repro.core.hashing import hash_unit
 from repro.core.sketches import (INVALID_IDX, Sketch, default_capacity,
                                  sampling_ranks, weight)
 
+from ..dispatch import interpret, resolve_use_pallas
 from ..hash_rank.hash_rank import BLOCK, LANES
 from ..hash_rank.ops import hash_rank_batched
 from .sketch_build import hash_rank_hist_pallas, rank_hist_pallas
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def resolve_use_pallas(use_pallas: bool | None) -> bool:
-    """None -> auto: Pallas kernels on TPU, fused XLA formulation elsewhere.
-
-    Unlike the estimation kernels (always-on, interpret off-TPU), the build
-    pipeline defaults to the XLA formulation off-TPU: construction is the
-    ingestion hot path and interpret-mode Pallas would serve only as a
-    parity oracle there (tests pass ``use_pallas=True`` explicitly).
-    """
-    if use_pallas is None:
-        return jax.default_backend() == "tpu"
-    return use_pallas
 
 
 # ---------------------------------------------------------------------------
@@ -101,8 +85,8 @@ def _pad_keys3d(keys: jnp.ndarray) -> jnp.ndarray:
 
 
 def _kth_smallest_bits_pallas(keys: jnp.ndarray, k: jnp.ndarray, *,
-                              hist0: jnp.ndarray | None = None,
-                              interpret: bool = True) -> jnp.ndarray:
+                              hist0: jnp.ndarray | None = None
+                              ) -> jnp.ndarray:
     """Same statistic via 4 Pallas histogram levels of 256 bins each.
 
     ``hist0``: optional precomputed level-0 (log-domain) histogram from the
@@ -116,7 +100,7 @@ def _kth_smallest_bits_pallas(keys: jnp.ndarray, k: jnp.ndarray, *,
             hist = hist0
         else:
             hist = rank_hist_pallas(keys3d, prefix, shift=shift,
-                                    interpret=interpret)
+                                    interpret=interpret())
         csum = jnp.cumsum(hist, axis=1)
         d_star = jnp.argmax(csum >= remaining[:, None], axis=1)
         below = jnp.where(
@@ -128,20 +112,21 @@ def _kth_smallest_bits_pallas(keys: jnp.ndarray, k: jnp.ndarray, *,
     return prefix
 
 
-def kth_smallest_ranks(keys: jnp.ndarray, k, *, use_pallas: bool = False,
+def kth_smallest_ranks(keys: jnp.ndarray, k, *,
+                       use_pallas: bool | None = None,
                        hist0: jnp.ndarray | None = None) -> jnp.ndarray:
     """Exact per-row k-th smallest of (D, n) nonnegative float32 keys.
 
     The shared selection primitive of the build pipeline: priority tau is
     ``kth_smallest_ranks(ranks, m+1)``, the threshold overflow cut is the
     (cap+1)-st smallest included rank, and adaptive tau's weight cutoff is
-    the (n-m+1)-st smallest weight.  Requires 1 <= k <= n.
+    the (n-m+1)-st smallest weight.  Requires 1 <= k <= n.  ``use_pallas``
+    resolves through ``kernels.dispatch`` (None: histogram kernels on TPU).
     """
     D, n = keys.shape
     k_arr = jnp.broadcast_to(jnp.asarray(k, jnp.int32), (D,))
-    if use_pallas:
-        bits = _kth_smallest_bits_pallas(keys, k_arr, hist0=hist0,
-                                         interpret=_use_interpret())
+    if resolve_use_pallas(use_pallas):
+        bits = _kth_smallest_bits_pallas(keys, k_arr, hist0=hist0)
     else:
         bits = _kth_smallest_bits_xla(keys, k_arr)
     return jax.lax.bitcast_convert_type(bits, jnp.float32)
@@ -305,7 +290,7 @@ def _front_end(A: jnp.ndarray, seed, variant: str,
         h, rank, hist = hash_rank_hist_pallas(
             v.reshape(D, n_pad // LANES, LANES),
             jnp.asarray(seed, jnp.int32), variant=variant,
-            interpret=_use_interpret())
+            interpret=interpret())
         # padding ranks are +inf; fold their counts out of the inf bin so
         # hist matches the unpadded block exactly
         pad_bin = np.int32(np.float32(np.inf).view(np.int32) >> 24)

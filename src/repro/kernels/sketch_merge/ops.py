@@ -29,17 +29,14 @@ import jax.numpy as jnp
 from repro import obs
 
 from ..intersect_estimate.ops import BucketizedSketch
-from ..sketch_build.ops import resolve_use_pallas
+from ..dispatch import interpret, resolve_use_pallas
 from .ref import merge_bucketized_ref
 from .sketch_merge import merge_bucketized_pallas
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def merged_tau_bucketized(A: BucketizedSketch, B: BucketizedSketch, seed, *,
-                          m: int, variant: str = "l2") -> jnp.ndarray:
+                          m: int, variant: str = "l2",
+                          use_pallas: bool | None = None) -> jnp.ndarray:
     """Per-row merged priority tau: the (m+1)-st smallest rank of the union
     candidates (kept ranks of both sides, b-duplicates masked, plus both
     published taus — DESIGN.md §14)."""
@@ -48,7 +45,7 @@ def merged_tau_bucketized(A: BucketizedSketch, B: BucketizedSketch, seed, *,
     return merged_tau_bucketized_payloads(
         BucketizedPayloads(A.idx, A.val[..., None], A.tau, A.dropped),
         BucketizedPayloads(B.idx, B.val[..., None], B.tau, B.dropped),
-        seed, m=m, variant=variant)
+        seed, m=m, variant=variant, use_pallas=use_pallas)
 
 
 @functools.partial(jax.jit, static_argnames=("variant", "use_pallas"))
@@ -57,7 +54,7 @@ def _merge_dispatch(a_idx, a_val, b_idx, b_val, tau, seed, *, variant: str,
     if use_pallas:
         return merge_bucketized_pallas(a_idx, a_val, b_idx, b_val, tau, seed,
                                        variant=variant,
-                                       interpret=_use_interpret())
+                                       interpret=interpret())
     return merge_bucketized_ref(a_idx, a_val, b_idx, b_val, tau, seed,
                                 variant=variant)
 
@@ -81,11 +78,13 @@ def merge_bucketized_corpora(A: BucketizedSketch, B: BucketizedSketch,
                          f"{B.idx.shape}")
     if obs.enabled() and not isinstance(A.idx, jax.core.Tracer):
         obs.kernel_launch("sketch_merge.merge")
+    use_pallas = resolve_use_pallas(use_pallas)
     if tau is None:
-        tau = merged_tau_bucketized(A, B, seed, m=m, variant=variant)
+        tau = merged_tau_bucketized(A, B, seed, m=m, variant=variant,
+                                    use_pallas=use_pallas)
     out_idx, out_val, new_drop = _merge_dispatch(
         A.idx, A.val, B.idx, B.val, tau, seed, variant=variant,
-        use_pallas=resolve_use_pallas(use_pallas))
+        use_pallas=use_pallas)
     dropped = A.dropped + B.dropped + new_drop
     return BucketizedSketch(out_idx, out_val,
                             jnp.asarray(tau, jnp.float32), dropped)

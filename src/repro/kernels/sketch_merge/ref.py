@@ -16,15 +16,20 @@ from repro.core.hashing import hash_unit
 from repro.core.sketches import INVALID_IDX, sampling_ranks, weight
 
 
+def slot_ranks(idx, val, seed, variant: str):
+    """Sampling rank h(idx)/w(val) of every slot; +inf at padding (val 0 ->
+    weight 0).  The one rank formula of the merge: the kernel's wrapper and
+    this oracle both call it, so their ``rank < tau`` cuts see equal bits."""
+    w = weight(jnp.asarray(val).astype(jnp.float32), variant)
+    return sampling_ranks(w, hash_unit(seed, idx))
+
+
 @functools.partial(jax.jit, static_argnames=("variant",))
 def merge_bucketized_ref(a_idx, a_val, b_idx, b_val, tau, seed, *,
                          variant: str = "l2"):
     """(D, B, S) x2 -> merged (out_idx, out_val, dropped (D,))."""
     D, B, S = a_idx.shape
-
-    def ranks(idx, val):
-        w = weight(val.astype(jnp.float32), variant)
-        return sampling_ranks(w, hash_unit(seed, idx))
+    ranks = lambda idx, val: slot_ranks(idx, val, seed, variant)
 
     tau3 = jnp.reshape(jnp.asarray(tau, jnp.float32), (D, 1, 1))
     keep_a = (a_idx != INVALID_IDX) & (ranks(a_idx, a_val) < tau3)

@@ -13,14 +13,22 @@ Multi-pod  : (2, 16, 16) axes ("pod", "data", "model") = 512 chips;
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the partitioner propagates shardings through the model's
+    # gathers and reshapes (explicit axes would demand an out_sharding on
+    # each of them)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for CPU tests (requires XLA_FLAGS device count)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))

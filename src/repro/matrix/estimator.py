@@ -70,7 +70,7 @@ def estimate_matrix_products(SA: MatrixSketch, SB: MatrixSketch, *,
     cheap on CPU) and is exact.  ``n_buckets``/``slots`` only apply to the
     kernel path.
     """
-    from repro.kernels.sketch_build import resolve_use_pallas
+    from repro.kernels.dispatch import resolve_use_pallas
     if resolve_use_pallas(use_pallas):
         from repro.kernels.matrix_sketch import (bucketize_matrix_sketches,
                                                  matrix_products_bucketized)

@@ -53,7 +53,7 @@ from repro.core import priority_sketch
 from repro.core.variance import chebyshev_estimate_ceiling
 from repro.kernels import (bucketize, estimate_tile_rows, round_up_pow2,
                            slot_inclusion_probs)
-from repro.kernels.sketch_build import resolve_use_pallas
+from repro.kernels.dispatch import resolve_use_pallas
 from repro.serve.resilience import RetryPolicy, ShardDownError, ShardHealth
 from repro.serve.sketch_service import _row_summaries
 from repro.serve.validation import check_vector

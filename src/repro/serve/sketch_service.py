@@ -301,14 +301,16 @@ class SketchIndex:
             if obs.enabled():
                 obs.quality_monitor().observe_ingest(self._tau[d], self._dropped[d])
 
-    def add_many(self, names: Sequence, matrix: np.ndarray) -> None:
+    def add_many(self, names: Sequence, matrix: np.ndarray, *,
+                 use_pallas: Optional[bool] = None) -> None:
         """Batch-ingest a (D, n) block: one fused linear-time build for all
         D vectors (``kernels.sketch_build``) + one vmapped bucketize, written
         straight into the pre-allocated bucketized blocks.
 
         Equivalent to D ``add`` calls (same sketches, same layout) but the
         construction is a single batched pipeline — no per-vector sort, no
-        per-vector dispatch (DESIGN.md §13).
+        per-vector dispatch (DESIGN.md §13).  ``use_pallas`` picks the build
+        kernels or their XLA formulation (None: ``kernels.dispatch``).
         """
         matrix = np.asarray(matrix, np.float32)
         if matrix.ndim != 2 or matrix.shape[0] != len(names):
@@ -325,7 +327,8 @@ class SketchIndex:
         with obs.op("serve.index.add_many") as sp:
             sp.set("rows", D)
             self._dim = matrix.shape[1]
-            sk = build_priority_corpus(jnp.asarray(matrix), self.m, self.seed)
+            sk = build_priority_corpus(jnp.asarray(matrix), self.m, self.seed,
+                                       use_pallas=use_pallas)
             bc = bucketize_corpus(sk, n_buckets=self.n_buckets,
                                   slots=self.slots)
             while len(self._names) + D > self._cap:
@@ -381,11 +384,12 @@ class SketchIndex:
         return self._device_corpus
 
     def query(self, vector: np.ndarray, top_k: Optional[int] = None, *,
-              mode: str = "plain"):
+              mode: str = "plain", use_pallas: bool = True):
         """Inner-product estimates of ``vector`` against every indexed
         vector; one bucketized kernel launch.  ``mode`` selects the plain
         Algorithm-2 path, the bias-aware exact-head correction, or the
-        DP-released corpus (class docstring; DESIGN.md §20)."""
+        DP-released corpus (class docstring; DESIGN.md §20).
+        ``use_pallas=False`` runs the jnp oracle, as in :meth:`all_pairs`."""
         if mode not in ("plain", "bias_aware", "private"):
             raise ValueError(f"unknown mode {mode!r}; expected "
                              "'plain'|'bias_aware'|'private'")
@@ -403,7 +407,8 @@ class SketchIndex:
                 sq = priority_sketch(jnp.asarray(vector), self.m, self.seed)
                 q = bucketize(sq, n_buckets=self.n_buckets, slots=self.slots)
                 est = np.asarray(query_corpus(
-                    q, self._corpus()), np.float64)[: len(self._names)]
+                    q, self._corpus(), use_pallas=use_pallas),
+                    np.float64)[: len(self._names)]
                 if mode == "bias_aware":
                     est = est + self._bias_aware_correction(
                         q, float(sq.tau), vector)
@@ -516,7 +521,8 @@ class SketchIndex:
             self._discovery = DiscoveryEngine(self)
         return self._discovery.top_k_for_query(vector, k, **kw)
 
-    def merge_from(self, other: "SketchIndex") -> None:
+    def merge_from(self, other: "SketchIndex", *,
+                   use_pallas: Optional[bool] = None) -> None:
         """Merge a partition-peer index into this one, row by row, without
         leaving the bucketized layout (DESIGN.md §14).
 
@@ -527,7 +533,8 @@ class SketchIndex:
         vectors are never touched.  Exact up to bucket-overflow drops on
         either side (counted in ``total_dropped``; rare for the default
         ``n_buckets >= 2 m`` sizing, DESIGN.md §4) — an entry already lost
-        to a full bucket cannot re-enter the union.
+        to a full bucket cannot re-enter the union.  ``use_pallas`` picks
+        the merge kernel or its jnp oracle (None: ``kernels.dispatch``).
         """
         if (other.m, other.n_buckets, other.slots, other.seed) != \
                 (self.m, self.n_buckets, self.slots, self.seed):
@@ -551,7 +558,7 @@ class SketchIndex:
                 jnp.asarray(other._idx[:D]), jnp.asarray(other._val[:D]),
                 jnp.asarray(other._tau[:D]), jnp.asarray(other._dropped[:D]))
             merged = merge_bucketized_corpora(mine, theirs, self.seed,
-                                              m=self.m)
+                                              m=self.m, use_pallas=use_pallas)
             self._idx[:D] = np.asarray(merged.idx)
             self._val[:D] = np.asarray(merged.val)
             self._tau[:D] = np.asarray(merged.tau)
@@ -712,7 +719,7 @@ class MatrixSketchStore:
     def query(self, matrix: np.ndarray) -> list:
         """Estimate ``Q^T A_c`` against every stored matrix in one launch;
         returns ``[(name, (d, d) ndarray), ...]`` in insertion order."""
-        from repro.kernels.sketch_build import resolve_use_pallas
+        from repro.kernels.dispatch import resolve_use_pallas
         if not self._names:
             raise ValueError("query on an empty store: add matrices before "
                              "querying")

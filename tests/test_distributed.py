@@ -38,8 +38,9 @@ from jax.sharding import Mesh
 from repro.configs import get_config
 from repro.models import init_params
 from repro.distributed import param_shardings
+from repro.launch.mesh import make_debug_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 cfg = get_config("qwen2-moe-a2.7b").reduced()
 params = init_params(cfg, jax.random.PRNGKey(0))
 sh = param_shardings(cfg, mesh)
