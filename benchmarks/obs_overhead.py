@@ -107,7 +107,6 @@ def _disabled_structural() -> dict:
     def hot():
         for _ in range(1000):
             obs.counter("repro_bench_total").inc()
-            obs.kernel_launch("bench.kernel")
             with obs.op("bench.op") as sp:
                 sp.set("k", 1)
     hot()

@@ -21,7 +21,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro import obs
 from repro.core.hashing import hash_bucket
 from repro.core.sketches import INVALID_IDX, Sketch
 
@@ -113,8 +112,6 @@ def query_corpus(q: BucketizedSketch, corpus: BucketizedSketch, *,
                  use_pallas: bool = True) -> jnp.ndarray:
     """(C,) inner product estimates of one query against a corpus: the
     all-pairs kernel with a single query row (one launch over the corpus)."""
-    if obs.enabled() and not isinstance(q.idx, jax.core.Tracer):
-        obs.kernel_launch("intersect_estimate.query")
     return _query_corpus_jit(q, corpus, use_pallas=use_pallas)
 
 
@@ -195,8 +192,6 @@ def estimate_all_pairs_bucketized(A: BucketizedSketch, B: BucketizedSketch, *,
     (D1, D2, B) — the knob the allpairs benchmark tunes per layout,
     DESIGN.md §17).
     """
-    if obs.enabled() and not isinstance(A.idx, jax.core.Tracer):
-        obs.kernel_launch("intersect_estimate.allpairs")
     a_p = slot_inclusion_probs(A, variant=variant)
     b_p = slot_inclusion_probs(B, variant=variant)
     return _allpairs_dispatch(A.idx, A.val, a_p, B.idx, B.val, b_p,
@@ -217,8 +212,6 @@ def estimate_tile_rows(a_idx, a_val, a_p, b_idx, b_val, b_p,
     that is what lets the engine visit an arbitrary, bound-ordered subset
     of tiles without recompiling or materializing the (D1, D2) matrix.
     """
-    if obs.enabled() and not isinstance(a_idx, jax.core.Tracer):
-        obs.kernel_launch("intersect_estimate.tile")
     return _estimate_tile_rows_jit(a_idx, a_val, a_p, b_idx, b_val, b_p,
                                    rows_a, rows_b, use_pallas=use_pallas)
 
@@ -240,8 +233,6 @@ def allpairs_moments(a_idx, a_val, a_p, b_idx, b_val, b_p, *, qt: int = QT,
     """(D1, D2, 6) co-moment channels (MOMENT_CHANNELS order) from bucketized
     corpora with caller-supplied per-slot inclusion probabilities — the
     join-correlation all-pairs path (DESIGN.md §7, §12)."""
-    if obs.enabled() and not isinstance(a_idx, jax.core.Tracer):
-        obs.kernel_launch("intersect_estimate.moments")
     return _allpairs_dispatch(a_idx, a_val, a_p, b_idx, b_val, b_p,
                               moments=True, qt=qt, ct=ct,
                               use_pallas=use_pallas)

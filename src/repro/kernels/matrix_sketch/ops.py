@@ -14,10 +14,8 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
-import jax
 import jax.numpy as jnp
 
-from repro import obs
 from repro.matrix.containers import MatrixSketch, stack_matrix_sketches
 
 __all__ = ["BucketizedMatrixSketch", "bucketize_matrix_sketches",
@@ -71,8 +69,6 @@ def matrix_products_bucketized(A: BucketizedMatrixSketch,
     """
     from repro.engine.bucketized import bucketized_products
     from repro.engine.containers import BucketizedPayloads
-    if obs.enabled() and not isinstance(A.idx, jax.core.Tracer):
-        obs.kernel_launch("matrix_sketch.products")
     return bucketized_products(
         BucketizedPayloads(A.idx, A.rows, A.tau, A.dropped),
         BucketizedPayloads(B.idx, B.rows, B.tau, B.dropped),

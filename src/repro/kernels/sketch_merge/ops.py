@@ -26,8 +26,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro import obs
-
 from ..intersect_estimate.ops import BucketizedSketch
 from ..dispatch import interpret, resolve_use_pallas
 from .ref import merge_bucketized_ref
@@ -76,8 +74,6 @@ def merge_bucketized_corpora(A: BucketizedSketch, B: BucketizedSketch,
     if A.idx.shape != B.idx.shape:
         raise ValueError(f"corpus shapes differ: {A.idx.shape} vs "
                          f"{B.idx.shape}")
-    if obs.enabled() and not isinstance(A.idx, jax.core.Tracer):
-        obs.kernel_launch("sketch_merge.merge")
     use_pallas = resolve_use_pallas(use_pallas)
     if tau is None:
         tau = merged_tau_bucketized(A, B, seed, m=m, variant=variant,

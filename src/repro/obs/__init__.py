@@ -33,10 +33,21 @@ under a ``jax.core.Tracer`` the call increments
 ``repro_engine_traces_total{fn=...}`` (retrace/recompile visibility)
 and returns the no-op span; concrete inputs get a real dispatch span.
 jax itself is never imported here — call sites pass the verdict in.
+
+**Profiler sink** (DESIGN.md §19): while a JAX profiler session is
+active, :func:`span`, :func:`op` and eager :func:`engine_op` also open a
+``jax.profiler.TraceAnnotation`` of the same name, whether or not
+``obs`` is enabled, so the program's steps land on the profiler's clock
+beside the device's ops.  Annotations carry the name only (attributes
+stay on the ring-buffer span).  The profiler is looked up in
+``sys.modules`` on each call: before ``jax`` is imported it counts as
+off, and with no session the only cost is that lookup and one
+``TraceAnnotation.is_enabled()`` call, neither of which allocates.
 """
 from __future__ import annotations
 
 import os
+import sys
 import threading
 
 from .metrics import (  # noqa: F401  (re-exported)
@@ -143,11 +154,47 @@ def histogram(name: str, help: str = "", labelnames=(), buckets=None):
     return _REGISTRY.histogram(name, help, labelnames, buckets)
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is
+    active, else None (also before ``jax`` has been imported)."""
+    ann = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    return ann if ann is not None and ann.is_enabled() else None
+
+
+class _Annotated:
+    """A profiler annotation around a span (a ring-buffer :class:`Span`,
+    an :class:`_Op` or :data:`NOOP_SPAN`); ``with`` binds the inner
+    span, so ``set`` keeps working.  Only constructed while a profiler
+    session is active."""
+
+    __slots__ = ("_ann", "_inner")
+
+    def __init__(self, ann, inner):
+        self._ann = ann
+        self._inner = inner
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self._inner.__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        try:
+            return self._inner.__exit__(exc_type, exc, tb)
+        finally:
+            self._ann.__exit__(exc_type, exc, tb)
+
+
+def _sink(name: str, inner):
+    """``inner``, wrapped in a profiler annotation named ``name`` while a
+    profiler session is active."""
+    ann = _profiler_annotation()
+    return inner if ann is None else _Annotated(ann(name), inner)
+
+
 def span(name: str):
-    """Plain tracing span (no metrics), or the shared no-op span."""
-    if not _ENABLED:
-        return NOOP_SPAN
-    return _TRACER.span(name)
+    """Plain tracing span (no metrics), or the shared no-op span; under
+    an active profiler session also a profiler annotation."""
+    return _sink(name, _TRACER.span(name) if _ENABLED else NOOP_SPAN)
 
 
 class _Op:
@@ -182,9 +229,7 @@ def op(name: str):
     """Timed span: records the span *and* count/latency/error metrics
     under the shared ``repro_op_*{op=name}`` families.  This is the
     default instrumentation primitive for serve/engine entry points."""
-    if not _ENABLED:
-        return NOOP_SPAN
-    return _Op(name)
+    return _sink(name, _Op(name) if _ENABLED else NOOP_SPAN)
 
 
 def engine_op(name: str, is_tracing: bool):
@@ -195,26 +240,18 @@ def engine_op(name: str, is_tracing: bool):
     compile of that entry point) and return the no-op span, so nothing
     is timed inside ``jax.jit``.  Eager: a real ``engine.<name>``
     dispatch span."""
-    if not _ENABLED:
-        return NOOP_SPAN
     if is_tracing:
-        _REGISTRY.counter(
-            "repro_engine_traces_total",
-            "jax trace/compile passes through engine entry points "
-            "(steady state: constant; growth = retrace churn)",
-            ("fn",)).labels(name).inc()
+        if _ENABLED:
+            _REGISTRY.counter(
+                "repro_engine_traces_total",
+                "jax trace/compile passes through engine entry points "
+                "(steady state: constant; growth = retrace churn)",
+                ("fn",)).labels(name).inc()
         return NOOP_SPAN
-    return _Op("engine." + name)
-
-
-def kernel_launch(kernel: str, n: int = 1) -> None:
-    """Count a kernel-wrapper dispatch:
-    ``repro_kernel_launches_total{kernel=...}``."""
-    if _ENABLED:
-        _REGISTRY.counter(
-            "repro_kernel_launches_total",
-            "dispatches through repro.kernels wrappers",
-            ("kernel",)).labels(kernel).inc(n)
+    if not _ENABLED and _profiler_annotation() is None:
+        return NOOP_SPAN
+    full = "engine." + name
+    return _sink(full, _Op(full) if _ENABLED else NOOP_SPAN)
 
 
 # ---------------------------------------------------------------------------

@@ -312,43 +312,50 @@ class SketchIndex:
         per-vector dispatch (DESIGN.md §13).  ``use_pallas`` picks the build
         kernels or their XLA formulation (None: ``kernels.dispatch``).
         """
-        matrix = np.asarray(matrix, np.float32)
-        if matrix.ndim != 2 or matrix.shape[0] != len(names):
-            raise ValueError("matrix must be (len(names), n)")
-        check_unique_names(names, self._name_set)
-        if self._dim is not None and matrix.shape[1] != self._dim:
-            raise ValueError(f"matrix has {matrix.shape[1]} coordinates but "
-                             f"this index was built over {self._dim}")
-        matrix = check_finite(matrix, "ingest matrix",
-                              nonfinite=self.nonfinite)
-        D = matrix.shape[0]
-        if D == 0:
-            return
         with obs.op("serve.index.add_many") as sp:
+            with obs.span("serve.index.add_many.validate"):
+                matrix = np.asarray(matrix, np.float32)
+                if matrix.ndim != 2 or matrix.shape[0] != len(names):
+                    raise ValueError("matrix must be (len(names), n)")
+                check_unique_names(names, self._name_set)
+                if self._dim is not None and matrix.shape[1] != self._dim:
+                    raise ValueError(
+                        f"matrix has {matrix.shape[1]} coordinates but "
+                        f"this index was built over {self._dim}")
+                matrix = check_finite(matrix, "ingest matrix",
+                                      nonfinite=self.nonfinite)
+            D = matrix.shape[0]
+            if D == 0:
+                return
             sp.set("rows", D)
             self._dim = matrix.shape[1]
-            sk = build_priority_corpus(jnp.asarray(matrix), self.m, self.seed,
-                                       use_pallas=use_pallas)
-            bc = bucketize_corpus(sk, n_buckets=self.n_buckets,
-                                  slots=self.slots)
-            while len(self._names) + D > self._cap:
-                self._grow()
-            d0 = len(self._names)
-            self._idx[d0:d0 + D] = np.asarray(bc.idx)
-            self._val[d0:d0 + D] = np.asarray(bc.val)
-            self._tau[d0:d0 + D] = np.asarray(bc.tau)
-            self._dropped[d0:d0 + D] = np.asarray(bc.dropped)
-            for k in range(D):
-                nz = np.flatnonzero(matrix[k])
-                self._set_head_row(d0 + k, nz, matrix[k, nz])
-            self._names.extend(names)
-            self._name_set.update(names)
-            self._refresh_row_stats(d0, d0 + D)
-            self._device_corpus = None
-            self._private_release = None
-            if obs.enabled():
-                obs.quality_monitor().observe_ingest(self._tau[d0:d0 + D],
-                                             self._dropped[d0:d0 + D])
+            with obs.span("serve.index.add_many.upload"):
+                dense = jnp.asarray(matrix)
+            with obs.span("serve.index.add_many.dispatch"):
+                sk = build_priority_corpus(dense, self.m, self.seed,
+                                           use_pallas=use_pallas)
+                bc = bucketize_corpus(sk, n_buckets=self.n_buckets,
+                                      slots=self.slots)
+            with obs.span("serve.index.add_many.fetch"):
+                while len(self._names) + D > self._cap:
+                    self._grow()
+                d0 = len(self._names)
+                self._idx[d0:d0 + D] = np.asarray(bc.idx)
+                self._val[d0:d0 + D] = np.asarray(bc.val)
+                self._tau[d0:d0 + D] = np.asarray(bc.tau)
+                self._dropped[d0:d0 + D] = np.asarray(bc.dropped)
+            with obs.span("serve.index.add_many.head"):
+                for k in range(D):
+                    nz = np.flatnonzero(matrix[k])
+                    self._set_head_row(d0 + k, nz, matrix[k, nz])
+                self._names.extend(names)
+                self._name_set.update(names)
+                self._refresh_row_stats(d0, d0 + D)
+                self._device_corpus = None
+                self._private_release = None
+                if obs.enabled():
+                    obs.quality_monitor().observe_ingest(
+                        self._tau[d0:d0 + D], self._dropped[d0:d0 + D])
 
     def _rollback_last(self, k: int) -> None:
         """Undo the last ``k`` appended rows, restoring padding state
@@ -399,23 +406,30 @@ class SketchIndex:
         with obs.op("serve.index.query") as sp:
             sp.set("rows", len(self._names))
             sp.set("mode", mode)
-            vector = check_vector(vector, "query vector", dim=self._dim,
-                                  nonfinite=self.nonfinite)
+            with obs.span("serve.index.query.validate"):
+                vector = check_vector(vector, "query vector", dim=self._dim,
+                                      nonfinite=self.nonfinite)
             if mode == "private":
                 est = self._query_private(vector)
             else:
-                sq = priority_sketch(jnp.asarray(vector), self.m, self.seed)
-                q = bucketize(sq, n_buckets=self.n_buckets, slots=self.slots)
-                est = np.asarray(query_corpus(
-                    q, self._corpus(), use_pallas=use_pallas),
-                    np.float64)[: len(self._names)]
+                with obs.span("serve.index.query.upload"):
+                    dense = jnp.asarray(vector)
+                with obs.span("serve.index.query.dispatch"):
+                    sq = priority_sketch(dense, self.m, self.seed)
+                    q = bucketize(sq, n_buckets=self.n_buckets,
+                                  slots=self.slots)
+                    est = query_corpus(q, self._corpus(),
+                                       use_pallas=use_pallas)
+                with obs.span("serve.index.query.fetch"):
+                    est = np.asarray(est, np.float64)[: len(self._names)]
+            with obs.span("serve.index.query.rank"):
                 if mode == "bias_aware":
                     est = est + self._bias_aware_correction(
                         q, float(sq.tau), vector)
-            if top_k is None:
-                return list(zip(self._names, est.tolist()))
-            order = _top_k_desc(est, top_k)
-            return [(self._names[i], float(est[i])) for i in order]
+                if top_k is None:
+                    return list(zip(self._names, est.tolist()))
+                order = _top_k_desc(est, top_k)
+                return [(self._names[i], float(est[i])) for i in order]
 
     def _bias_aware_correction(self, q, tau_q: float,
                                vector: np.ndarray) -> np.ndarray:

@@ -21,6 +21,11 @@ from repro.obs.tracing import NOOP_SPAN, Tracer
 from repro.obs.quality import (CanaryMonitor, QualityMonitor,
                                chebyshev_halfwidth, observe_recovery)
 
+ADD_MANY_STEPS = [f"serve.index.add_many.{s}" for s in
+                  ("validate", "upload", "dispatch", "fetch", "head")]
+QUERY_STEPS = [f"serve.index.query.{s}" for s in
+               ("validate", "upload", "dispatch", "fetch", "rank")]
+
 
 @pytest.fixture(autouse=True)
 def _obs_clean():
@@ -57,11 +62,16 @@ def test_disabled_accessors_return_shared_singletons():
 
 def test_disabled_records_nothing():
     obs.counter("repro_never_total", "x").inc()
-    obs.kernel_launch("never.kernel")
+    with obs.span("never.span"):
+        pass
     with obs.op("never.op"):
         pass
+    with obs.engine_op("never.fn", False):
+        pass
     assert obs.snapshot() == {}
+    assert obs.registry().value("repro_op_total", "never.op") == 0.0
     assert obs.tracer().events() == []
+    assert obs.tracer().spans_started == 0
 
 
 def test_disabled_hot_loop_allocates_nothing():
@@ -71,7 +81,8 @@ def test_disabled_hot_loop_allocates_nothing():
     def hot():
         for _ in range(1000):
             obs.counter("repro_hot_total").inc()
-            obs.kernel_launch("hot.kernel")
+            with obs.span("hot.span"):
+                pass
             with obs.op("hot.op") as sp:
                 sp.set("k", 1)
     hot()  # warm up: interned ints, bytecode, method caches
@@ -83,6 +94,8 @@ def test_disabled_hot_loop_allocates_nothing():
     # tracemalloc's own bookkeeping shows up as a small constant; per-call
     # allocation over 3000 accessor hits would be tens of kilobytes
     assert now - base < 2048, f"disabled path allocated {now - base} bytes"
+    assert obs.tracer().spans_started == 0
+    assert obs.registry().value("repro_op_total", "hot.op") == 0.0
 
 
 def test_enable_disable_flip_without_stale_handles():
@@ -251,6 +264,142 @@ def test_engine_op_tracing_verdict():
 
 
 # ---------------------------------------------------------------------------
+# profiler sink: program spans on the JAX profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_profiler_off_keeps_singletons_and_allocates_nothing():
+    """With jax loaded but no profiler session, the accessors still hand
+    out the shared no-op span and the hot loop (which now asks the
+    profiler on every call) still allocates nothing."""
+    import jax.profiler
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert obs.span("anything") is NOOP_SPAN
+    assert obs.op("anything") is NOOP_SPAN
+    assert obs.engine_op("anything", False) is NOOP_SPAN
+
+    def hot():
+        for _ in range(1000):
+            with obs.span("hot.span"):
+                pass
+            with obs.op("hot.op") as sp:
+                sp.set("k", 1)
+            with obs.engine_op("hot.fn", False):
+                pass
+    hot()
+    tracemalloc.start()
+    base, _ = tracemalloc.get_traced_memory()
+    hot()
+    now, _ = tracemalloc.get_traced_memory()
+    tracemalloc.stop()
+    assert now - base < 2048, f"profiler-off path allocated {now - base}"
+
+
+def _profiled(tmp_path, body):
+    """Run ``body`` inside a JAX profiler session and an annotation of the
+    caller's own; return the host events of the caller's thread as
+    (name, start_ns, end_ns)."""
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("test.caller"):
+            body()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events]
+            if any(n == "test.caller" for n, _, _ in evs):
+                return evs
+    raise AssertionError("no host line holds the caller's annotation")
+
+
+def _serve_once():
+    from repro.serve import SketchIndex
+    rng = np.random.default_rng(12)
+    idx = SketchIndex(m=32, n_buckets=64, seed=11)
+    idx.add_many(["w0", "w1"], rng.normal(size=(2, 128)).astype(np.float32))
+    idx.query(rng.normal(size=128).astype(np.float32))    # compile first
+    V = rng.normal(size=(3, 128)).astype(np.float32)
+    return lambda: (idx.add_many([f"v{i}" for i in range(3)], V),
+                    idx.query(V[0], top_k=2))
+
+
+def _within(evs, name, parent):
+    (s, e), = [(s, e) for n, s, e in evs if n == name]
+    (ps, pe), = [(s, e) for n, s, e in evs if n == parent]
+    return ps <= s and e <= pe
+
+
+@pytest.mark.parametrize("enabled", [False, True], ids=["disabled",
+                                                        "enabled"])
+def test_profiler_session_records_service_steps(tmp_path, enabled):
+    """Inside a profiler session ``add_many`` and ``query`` leave one host
+    event per step, each inside its call, on the caller's thread, whether
+    or not obs is enabled; disabled, nothing reaches the ring or the
+    registry."""
+    serve = _serve_once()
+    if enabled:
+        obs.enable()
+    evs = _profiled(tmp_path, serve)
+    for parent, steps in (("serve.index.add_many", ADD_MANY_STEPS),
+                          ("serve.index.query", QUERY_STEPS)):
+        assert _within(evs, parent, "test.caller")
+        for step in steps:
+            assert _within(evs, step, parent), step
+    if not enabled:
+        assert obs.tracer().events() == [] and obs.snapshot() == {}
+    else:
+        r = obs.registry()
+        assert r.value("repro_op_total", "serve.index.add_many") == 1.0
+        assert r.value("repro_op_total", "serve.index.query") == 1.0
+
+
+def test_profiler_annotation_names_equal_ring_span_names(tmp_path):
+    serve = _serve_once()
+    obs.enable()
+    evs = _profiled(tmp_path, serve)
+    ring = sorted(s.name for s in obs.tracer().events())
+    ours = sorted(n for n, _, _ in evs if n.startswith(("serve.", "engine.")))
+    assert ring == ours
+    assert set(ADD_MANY_STEPS + QUERY_STEPS) <= set(ring)
+
+
+def test_profiler_annotation_keeps_span_api_and_balance(tmp_path):
+    """Under a session the accessors return annotated spans whose ``with``
+    target still takes attributes; a raising body closes the annotation
+    and, enabled, marks the ring span failed; eager engine ops are
+    annotated as ``engine.<fn>``, traced ones are not."""
+    obs.enable()
+
+    def body():
+        with obs.op("serve.outer") as sp:
+            sp.set("rows", 2)
+            with pytest.raises(RuntimeError):
+                with obs.span("serve.outer.step"):
+                    raise RuntimeError("boom")
+            with obs.engine_op("estimate_product", False):
+                pass
+            assert obs.engine_op("estimate_product", True) is NOOP_SPAN
+    evs = _profiled(tmp_path, body)
+    assert _within(evs, "serve.outer.step", "serve.outer")
+    assert _within(evs, "engine.estimate_product", "serve.outer")
+    assert obs.tracer().active_depth() == 0
+    step = next(s for s in obs.tracer().events()
+                if s.name == "serve.outer.step")
+    assert step.ok is False
+    outer = next(s for s in obs.tracer().events() if s.name == "serve.outer")
+    assert outer.attrs == {"rows": 2}
+    assert obs.registry().value("repro_engine_traces_total",
+                                "estimate_product") == 1.0
+
+
+# ---------------------------------------------------------------------------
 # quality: ingest, recovery, canary SLO
 # ---------------------------------------------------------------------------
 
@@ -373,11 +522,10 @@ def test_sketch_index_hooks_record(tmp_path):
     assert r.value("repro_op_total", "serve.index.query") == 1.0
     assert r.value("repro_op_total", "serve.index.all_pairs") == 1.0
     assert r.value("repro_quality_ingest_rows_total") == 3
-    snap = obs.snapshot()
-    kernels = {s["labels"]["kernel"]
-               for s in snap["repro_kernel_launches_total"]["series"]}
-    assert "intersect_estimate.query" in kernels
-    assert "intersect_estimate.allpairs" in kernels
+    names = [s.name for s in obs.tracer().events()]
+    for step in ADD_MANY_STEPS + QUERY_STEPS:
+        assert names.count(step) == 1, step
+    assert names.count("serve.index.all_pairs") == 1
     assert obs.tracer().active_depth() == 0
 
 
