@@ -44,7 +44,8 @@ from .intersect_estimate import (MOMENT_CHANNELS, BucketizedSketch,
                                  estimate_all_pairs_bucketized,
                                  estimate_tile_rows,
                                  intersect_estimate_ref, query_corpus,
-                                 round_up_pow2, slot_inclusion_probs)
+                                 round_up_pow2, sketch_and_query,
+                                 slot_inclusion_probs)
 
 __all__ = [
     "hash_rank", "hash_rank_batched", "hash_rank_batched_ref", "hash_rank_ref",
@@ -59,7 +60,8 @@ __all__ = [
     "countsketch_kernel", "countsketch_ref",
     "jl_project", "jl_ref",
     "BucketizedSketch", "bucketize", "bucketize_corpus", "bucketize_payloads",
-    "intersect_estimate_ref", "query_corpus", "allpairs_estimate_ref",
+    "intersect_estimate_ref", "query_corpus", "sketch_and_query",
+    "allpairs_estimate_ref",
     "estimate_all_pairs_bucketized", "estimate_tile_rows",
     "allpairs_moments",
     "slot_inclusion_probs", "round_up_pow2", "MOMENT_CHANNELS",

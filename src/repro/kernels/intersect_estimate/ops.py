@@ -9,7 +9,9 @@ carries its precomputed inclusion probabilities alongside the values.
 
 Estimation entry points:
 
-- ``query_corpus``       one query vs a corpus (serving path)
+- ``query_corpus``       one query vs a corpus
+- ``sketch_and_query``   one dense query vector vs a corpus: sketch,
+  bucketize and the ``query_corpus`` launch as one program (serving path)
 - ``estimate_all_pairs_bucketized``  (D1, D2) estimate matrix in one launch
 - ``allpairs_moments``   (D1, D2, 6) co-moment channels for join-correlation
 """
@@ -21,7 +23,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.hashing import hash_bucket
+from repro.core.priority import priority_sketch
 from repro.core.sketches import INVALID_IDX, Sketch
 
 from ..dispatch import interpret
@@ -127,6 +131,28 @@ def _query_corpus_jit(q: BucketizedSketch, corpus: BucketizedSketch, *,
                            corpus.idx, corpus.val,
                            slot_inclusion_probs(corpus),
                            moments=False, qt=QT, ct=CT)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("m", "n_buckets", "slots",
+                                             "use_pallas"))
+def sketch_and_query(vector: jnp.ndarray, corpus: BucketizedSketch, seed, *,
+                     m: int, n_buckets: int, slots: int,
+                     use_pallas: bool = True):
+    """Serve one dense query vector as one compiled program: the reference
+    priority sketch of ``vector``, its bucketize, then ``_query_corpus_jit``
+    (called, not inlined, so the kernel keeps that name in the program).
+
+    Returns ``(est, q)``: the (C,) estimates and the bucketized query, whose
+    ``tau`` the bias-aware correction needs.  Bit for bit the eager
+    ``priority_sketch`` -> ``bucketize`` -> ``query_corpus`` chain.
+    """
+    # the body runs only while tracing: one bump per compile of this
+    # program (jit boundary rule, DESIGN.md §19)
+    obs.counter("repro_query_program_compiles_total",
+                "compiles of the served query program").inc()
+    q = bucketize(priority_sketch(vector, m, seed), n_buckets=n_buckets,
+                  slots=slots)
+    return _query_corpus_jit(q, corpus, use_pallas=use_pallas), q
 
 
 def _pad_rows(idx, val, p, tile: int):

@@ -33,8 +33,8 @@ from repro.serve.validation import (check_finite, check_nonfinite_policy,
 from repro.kernels import (BucketizedSketch, bucketize, bucketize_corpus,
                            build_priority_corpus,
                            estimate_all_pairs_bucketized,
-                           merge_bucketized_corpora, query_corpus,
-                           round_up_pow2)
+                           merge_bucketized_corpora, round_up_pow2,
+                           sketch_and_query)
 from repro.matrix import (MatrixSketch, estimate_matrix_product,
                           estimate_matrix_products, priority_matrix_sketch)
 
@@ -393,7 +393,8 @@ class SketchIndex:
     def query(self, vector: np.ndarray, top_k: Optional[int] = None, *,
               mode: str = "plain", use_pallas: bool = True):
         """Inner-product estimates of ``vector`` against every indexed
-        vector; one bucketized kernel launch.  ``mode`` selects the plain
+        vector; one compiled program per corpus shape (query sketch,
+        bucketize, one bucketized kernel launch).  ``mode`` selects the plain
         Algorithm-2 path, the bias-aware exact-head correction, or the
         DP-released corpus (class docstring; DESIGN.md §20).
         ``use_pallas=False`` runs the jnp oracle, as in :meth:`all_pairs`."""
@@ -415,17 +416,18 @@ class SketchIndex:
                 with obs.span("serve.index.query.upload"):
                     dense = jnp.asarray(vector)
                 with obs.span("serve.index.query.dispatch"):
-                    sq = priority_sketch(dense, self.m, self.seed)
-                    q = bucketize(sq, n_buckets=self.n_buckets,
-                                  slots=self.slots)
-                    est = query_corpus(q, self._corpus(),
-                                       use_pallas=use_pallas)
+                    # uint32 on the host: the seeds the eager hash took,
+                    # not only those a traced int32 holds
+                    est, q = sketch_and_query(
+                        dense, self._corpus(), np.uint32(self.seed), m=self.m,
+                        n_buckets=self.n_buckets, slots=self.slots,
+                        use_pallas=use_pallas)
                 with obs.span("serve.index.query.fetch"):
                     est = np.asarray(est, np.float64)[: len(self._names)]
             with obs.span("serve.index.query.rank"):
                 if mode == "bias_aware":
                     est = est + self._bias_aware_correction(
-                        q, float(sq.tau), vector)
+                        q, float(q.tau), vector)
                 if top_k is None:
                     return list(zip(self._names, est.tolist()))
                 order = _top_k_desc(est, top_k)

@@ -529,6 +529,39 @@ def test_sketch_index_hooks_record(tmp_path):
     assert obs.tracer().active_depth() == 0
 
 
+def test_query_program_compiles_once_per_corpus_shape():
+    """The served query compiles once per padded corpus shape: repeated
+    queries add nothing, an ``add_many`` that doubles the padded rows adds
+    one; each query's step spans stay its children, in order."""
+    from repro.kernels import sketch_and_query
+    from repro.serve import SketchIndex
+    sketch_and_query.clear_cache()
+    obs.enable()
+    rng = np.random.default_rng(16)
+    idx = SketchIndex(m=32, n_buckets=64, seed=11)
+    V = rng.normal(size=(16, 128)).astype(np.float32)
+    idx.add_many([f"v{i}" for i in range(8)], V[:8])
+    compiles = lambda: obs.registry().value(
+        "repro_query_program_compiles_total")
+    for v in V[:3]:
+        idx.query(v, top_k=2)
+    assert compiles() == 1
+    idx.add_many([f"v{i}" for i in range(8, 16)], V[8:])
+    assert idx._corpus().idx.shape[0] == 16
+    for v in V[:2]:
+        idx.query(v, top_k=2)
+    assert compiles() == 2
+    spans = obs.tracer().events()
+    queries = [s for s in spans if s.name == "serve.index.query"]
+    assert len(queries) == 5
+    for parent in queries:
+        steps = sorted((s for s in spans if s.parent_id == parent.span_id),
+                       key=lambda s: s.t0)
+        assert [s.name for s in steps] == QUERY_STEPS
+        assert parent.t0 <= steps[0].t0
+        assert steps[-1].t0 + steps[-1].dur <= parent.t0 + parent.dur
+
+
 def test_discovery_scanstats_fold_into_registry():
     from repro.serve import DiscoveryEngine, SketchIndex
     obs.enable()

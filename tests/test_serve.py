@@ -250,3 +250,63 @@ def test_sketch_index_merge_from_mismatch_raises():
     misnamed.add("b", rng.normal(size=64).astype(np.float32))
     with pytest.raises(ValueError, match="names must align"):
         base.merge_from(misnamed)
+
+
+def _query_vector(kind: str, n: int = 2000) -> np.ndarray:
+    """More than m nonzeros (tau finite), at most m (tau = inf), or many
+    nonzeros of one magnitude (tied weights)."""
+    rng = np.random.default_rng(13)
+    v = np.zeros(n, np.float32)
+    nnz = {"sampled": 400, "kept_whole": 20, "tied": 400}[kind]
+    ii = rng.choice(n, nnz, replace=False)
+    v[ii] = (rng.choice([-1.0, 1.0], nnz) if kind == "tied"
+             else rng.uniform(-1, 1, nnz))
+    return v
+
+
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["pallas", "oracle"])
+@pytest.mark.parametrize("kind", ["sampled", "kept_whole", "tied"])
+def test_sketch_and_query_matches_eager_chain(kind, use_pallas):
+    """The served query program equals, bit for bit, the eager chain it
+    replaced: ``priority_sketch`` -> ``bucketize`` -> ``query_corpus``."""
+    from repro.kernels import bucketize, query_corpus, sketch_and_query
+    rng = np.random.default_rng(14)
+    # a seed above int32, as the eager hash took it
+    idx = SketchIndex(m=64, n_buckets=128, slots=4, seed=2**31 + 7)
+    for d, u in enumerate(_sparse_vecs(rng, 6, n=2000, nnz=300)):
+        idx.add(f"v{d}", u)
+    v = _query_vector(kind)
+    sq = priority_sketch(jnp.asarray(v), idx.m, idx.seed)
+    assert np.isfinite(float(sq.tau)) == (kind != "kept_whole")
+    q_ref = bucketize(sq, n_buckets=idx.n_buckets, slots=idx.slots)
+    est_ref = query_corpus(q_ref, idx._corpus(), use_pallas=use_pallas)
+    est, q = sketch_and_query(jnp.asarray(v), idx._corpus(),
+                              np.uint32(idx.seed),
+                              m=idx.m, n_buckets=idx.n_buckets,
+                              slots=idx.slots, use_pallas=use_pallas)
+    np.testing.assert_array_equal(np.asarray(est), np.asarray(est_ref))
+    for got, want in zip(q, q_ref):      # idx, val, tau, dropped
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    served = idx.query(v, use_pallas=use_pallas)
+    np.testing.assert_array_equal([e for _, e in served],
+                                  np.asarray(est_ref, np.float64)[:6])
+
+
+def test_sketch_index_bias_aware_query_unchanged():
+    """``mode="bias_aware"`` adds the exact-head correction to the same
+    estimates as before the query became one program."""
+    from repro.kernels import bucketize, query_corpus
+    rng = np.random.default_rng(15)
+    idx = SketchIndex(m=64, n_buckets=128, slots=4, head_h=8)
+    idx.add_many([f"v{d}" for d in range(6)],
+                 np.stack(_sparse_vecs(rng, 6, n=2000, nnz=300)))
+    v = _query_vector("sampled")
+    sq = priority_sketch(jnp.asarray(v), idx.m, idx.seed)
+    q = bucketize(sq, n_buckets=idx.n_buckets, slots=idx.slots)
+    est = np.asarray(query_corpus(q, idx._corpus()), np.float64)[:6]
+    want = est + idx._bias_aware_correction(q, float(sq.tau), v)
+    got = idx.query(v, mode="bias_aware")
+    assert [n for n, _ in got] == [f"v{d}" for d in range(6)]
+    np.testing.assert_array_equal([e for _, e in got], want)
+    assert not np.array_equal(want, est)   # the correction did something

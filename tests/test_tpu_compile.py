@@ -4,17 +4,21 @@ Interpret mode accepts blocks and casts that Mosaic refuses, so each
 kernel of the served path is compiled here with ``interpret=False`` for a
 described (not attached) v5e chip, at the shapes ``chip_smoke.py`` runs:
 a (256, 2^18) build block, an 8192-row corpus of (512, 4) buckets, a
-512-row merge, 64-row discovery tiles.  Each compile must hold a Mosaic
-kernel (``tpu_custom_call``) and fit the chip's memory.  The topology is
-described inside a fixture, so collection never loads the TPU library.
+512-row merge, 64-row discovery tiles; and the served query program at
+the query cell's shapes (a 2^20 vector against 4096 rows of (1024, 4)).
+Each compile must hold a Mosaic kernel (``tpu_custom_call``) and fit the
+chip's memory.  The topology is described inside a fixture, so collection
+never loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import BucketizedSketch, dispatch, sketch_and_query
 from repro.kernels.hash_rank.hash_rank import (hash_rank_batched_pallas,
                                                hash_rank_pallas)
 from repro.kernels.intersect_estimate.intersect_estimate import \
@@ -97,6 +101,35 @@ def test_query_row_compiles(one_chip):
     # SketchIndex.query: one bucketized query row against the corpus
     _compile(lambda *a: allpairs_estimate_pallas(*a, qt=1, interpret=False),
              one_chip, *_corpus(1), *_corpus(D))
+
+
+def test_served_query_program_compiles(one_chip, monkeypatch):
+    # SketchIndex.query as one program at the query cell's shapes: sketch of
+    # a 2^20 vector, bucketize, the all-pairs launch against 4096 rows of
+    # (1024, 4); its kernel keeps the name the all-pairs roofline reads
+    from bench.costs import KERNELS
+    from repro.kernels.intersect_estimate import ops
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    sds = lambda shape, t: jax.ShapeDtypeStruct(shape, t, sharding=one_chip)
+    rows, nb = 4096, 1024
+    corpus = BucketizedSketch(sds((rows, nb, S), i32), sds((rows, nb, S), f32),
+                              sds((rows,), f32), sds((rows,), i32))
+    jits = (sketch_and_query, ops._query_corpus_jit)
+    for fn in jits:       # no trace lowered for the CPU is reused, or kept
+        fn.clear_cache()
+    try:
+        compiled = sketch_and_query.lower(
+            sds((1 << 20,), f32), corpus, sds((), u32), m=512, n_buckets=nb,
+            slots=S, use_pallas=True).compile()
+    finally:
+        for fn in jits:
+            fn.clear_cache()
+    kernels = [ln.strip() for ln in compiled.as_text().splitlines()
+               if "tpu_custom_call" in ln]
+    assert kernels and all(re.search(KERNELS["allpairs"], ln)
+                           for ln in kernels), kernels
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
 
 
 def test_discovery_tile_compiles(one_chip):
