@@ -3,13 +3,16 @@
 
     python bench/control.py --workload tpch-z2-m512.query --seeds 1,2,3
 
-The control is the plain reference computed in bfloat16 (values, weights,
-hashes, ranks, tau, probabilities and products rounded; float32 sums), put
-in the program's place: for a query cell it answers every request of the
-cell's schedule at its own rate and window, for an ingest cell it stores
-every column of the pool.  Its numbers are compared with the float32
-reference exactly as a run compares the program's; each has to exceed one
-of the cell's limits.  Prints one JSON line per seed.  Needs no chip.
+The control is the cell's reference in the precision below the
+configuration's, put in the program's place; the cell's traffic kind
+decides what it answers or stores (``control_numbers``, ``bench.kinds``).
+For the inner-product kinds it is the plain reference in bfloat16 (values,
+weights, hashes, ranks, tau, probabilities and products rounded; float32
+sums): a query cell's control answers every request of the cell's schedule
+at its own rate and window, an ingest cell's stores two passes over the
+pool.  Its numbers are compared with the reference exactly as a run
+compares the program's; each seed's have to exceed one of the cell's
+limits.  Prints one JSON line per seed.  Needs no chip.
 """
 from __future__ import annotations
 
@@ -38,10 +41,7 @@ def main(argv=None) -> int:
     for seed in map(int, args.seeds.split(",")):
         kind = drive.traffic_kind(cell)(cell, seed)
         kind.setup(seconds, program=False)
-        if isinstance(kind, drive.BulkIngest):
-            numbers = kind.control_numbers(2 * len(kind.blocks))
-        else:
-            numbers = kind.control_numbers()
+        numbers = kind.control_numbers()
         failed = any(numbers[k] > cell.limits[k] for k in cell.limits)
         fails &= failed
         print(json.dumps({"workload": cell.name, "seed": seed,

@@ -1,11 +1,14 @@
 """Data generators, one module per configuration family, named by the
 ``data.generator`` key of a configuration file.
 
-Each module offers ``corpus_block(data, seed, b, rows)`` -> ``Block`` (a dense
-``(rows, universe)`` float32 block and the same columns as CSR), and
-optionally ``query_pool(data, seed, corpus, indexed, fresh)``.  They are
-copies, kept here so that a change to the program cannot move the
-yardstick.
+A module that the dense kinds of traffic (``open_query``, ``bulk_ingest``)
+read offers ``corpus_block(data, seed, b, rows)`` -> ``Block`` (a dense
+``(rows, universe)`` float32 block and the same columns as CSR), and, for
+``open_query``, ``query_pool(data, seed, corpus, indexed, fresh)``.  That
+contract binds only the kinds that call it: a new kind may read any
+function of a new module, such as keyed CSR tables with no dense copy.
+The generators are copies, kept here so that a change to the program
+cannot move the yardstick.
 """
 from __future__ import annotations
 

@@ -3,14 +3,16 @@
 A cell (an entry of ``workloads``) names a configuration and a traffic mix.
 Its files, all under the benchmark's directory (the first of ``paths``):
 
-- ``configs``' ``file``: the deployment (index parameters, data generator);
-- ``traffic/<traffic>.json``: the traffic mix;
+- ``configs``' ``file``: the deployment (its sizes and what its kind reads);
+- ``traffic/<traffic>.json``: the traffic mix, with its ``kind``;
+- ``kinds/<kind>.py``: that kind of traffic, with the surface it drives
+  and the reference it is checked against (contract in ``bench.kinds``);
 - ``limits/<workload>.json``: the limit of each number ``correct`` compares;
 - ``metrics/<metric>.py``: one reader per per-layer metric;
 - ``peaks.json``: chip peaks by ``device_kind``.
 
-Adding a configuration, a traffic mix or a metric is adding files and
-entries; nothing here names one.
+Adding a configuration, a traffic mix, a kind of traffic or a metric is
+adding files and entries; nothing here names one.
 """
 from __future__ import annotations
 
@@ -18,6 +20,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import sys
 
 
 class SpecError(ValueError):
@@ -75,6 +78,12 @@ def load_cell(root: str, workload: str) -> Cell:
                         "config")
     traffic = _read_json(os.path.join(bench_dir, "traffic",
                                       w["traffic"] + ".json"), "traffic")
+    kind = traffic.get("kind")
+    if not (isinstance(kind, str) and kind.isidentifier()
+            and os.path.isfile(kind_path(bench_dir, kind))):
+        raise SpecError(f"traffic {w['traffic']!r} names kind {kind!r}, "
+                        f"which has no file {kind_path(bench_dir, str(kind))}"
+                        f"; kinds found: {kinds(bench_dir)}")
     limits = _read_json(os.path.join(bench_dir, "limits", workload + ".json"),
                         "limits")
     e2e = [m for m in spec["end_to_end"]
@@ -93,15 +102,36 @@ def metric_path(bench_dir: str, name: str) -> str:
     return os.path.join(bench_dir, "metrics", name + ".py")
 
 
+def kind_path(bench_dir: str, kind: str) -> str:
+    return os.path.join(bench_dir, "kinds", kind + ".py")
+
+
+def kinds(bench_dir: str) -> list:
+    """The kinds of traffic that have a file under ``bench_dir``."""
+    try:
+        names = os.listdir(os.path.join(bench_dir, "kinds"))
+    except OSError:
+        return []
+    return sorted(f[:-3] for f in names
+                  if f.endswith(".py") and f != "__init__.py")
+
+
+def load_file(bench_dir: str, rel: str):
+    """The module in ``<bench_dir>/<rel>``, loaded from that file, so that
+    a checkout or a test's root can hold one the package does not."""
+    mod_name = "bench_file_" + "".join(
+        c if c.isalnum() else "_" for c in rel[:-3])
+    sp = importlib.util.spec_from_file_location(
+        mod_name, os.path.join(bench_dir, rel))
+    mod = importlib.util.module_from_spec(sp)
+    sys.modules[mod_name] = mod
+    sp.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(bench_dir: str, name: str):
     """The ``read(records)`` function of a per-layer metric's reader."""
-    path = metric_path(bench_dir, name)
-    mod_name = "bench_metric_" + "".join(
-        c if c.isalnum() else "_" for c in name)
-    sp = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(sp)
-    sp.loader.exec_module(mod)
-    return mod.read
+    return load_file(bench_dir, f"metrics/{name}.py").read
 
 
 def peaks(bench_dir: str, device_kind: str) -> dict:

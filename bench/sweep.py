@@ -1,14 +1,16 @@
 #!/usr/bin/env python3
-"""Find the highest query rate a query cell sustains (its knee), on the chip.
+"""Find the highest request rate a cell sustains (its knee), on the chip.
 
     python bench/sweep.py --workload tpch-z2-m512.query --seed 7 --seconds 20
 
-Builds the cell's corpus once, serves requests back to back for
-``--seconds`` (the closed-loop rate ``mu``), then offers open-loop Poisson
+Sets the cell up once through its traffic kind, serves requests back to
+back for ``--seconds`` (the closed-loop rate ``mu``), then offers open-loop
 load at fractions of ``mu`` and prints, per rate, the completions per
-second, the median and 95th-percentile latency, and the latency growth from
-the first to the last quarter of the window (a growing backlog).  The
-sustained rate is written into the cell's traffic file by hand.
+second, the median latency, the kind's end-to-end numbers, and the latency
+growth from the first to the last quarter of the window (a growing
+backlog).  The sustained rate is written into the cell's traffic file by
+hand.  Only a kind with ``serve_one`` and ``schedule`` (``bench.kinds``)
+can be swept; for any other the sweep exits non-zero.
 """
 from __future__ import annotations
 
@@ -29,21 +31,27 @@ def main(argv=None) -> int:
     ap.add_argument("--fractions", default="0.6,0.7,0.8,0.9,1.0")
     args = ap.parse_args(argv)
     sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
+    from bench import drive, spec
+    cell = spec.load_cell(ROOT, args.workload)
+    kind_cls = drive.traffic_kind(cell)
+    if not (hasattr(kind_cls, "serve_one") and hasattr(kind_cls, "schedule")):
+        print(f"sweep: {cell.name}'s traffic kind {cell.traffic['kind']!r} "
+              "serves no single request closed loop (no serve_one and "
+              "schedule), so it has no knee to sweep", file=sys.stderr)
+        return 2
     os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(ROOT, ".jax_cache")
     import jax
     import numpy as np
-    from bench import drive, spec
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     if jax.devices()[0].platform != "tpu":
         print("sweep: needs a TPU", file=sys.stderr)
         return 1
-    cell = spec.load_cell(ROOT, args.workload)
-    kind = drive.OpenQuery(cell, args.seed)
+    kind = kind_cls(cell, args.seed)
     kind.setup(args.seconds)
     null = lambda name: contextlib.nullcontext()
     t0, n = drive.clock(), 0
     while drive.clock() - t0 < args.seconds:
-        kind._answers([kind.pool[n % len(kind.pool)]])
+        kind.serve_one(n)
         n += 1
     mu = n / (drive.clock() - t0)
     rows = [{"rate": "closed loop", "per_s": mu}]
@@ -55,8 +63,7 @@ def main(argv=None) -> int:
         q = max(1, lat.size // 4)
         row = {"fraction": f, "rate": f * mu,
                "per_s": lat.size / win.seconds,
-               "p50_ms": float(np.median(lat)) * 1e3,
-               "p95_ms": win.e2e["query_p95_ms"],
+               "p50_ms": float(np.median(lat)) * 1e3, **win.e2e,
                "first_quarter_ms": float(np.mean(lat[:q])) * 1e3,
                "last_quarter_ms": float(np.mean(lat[-q:])) * 1e3,
                "failed": win.failed}

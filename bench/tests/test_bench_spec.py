@@ -83,8 +83,13 @@ def test_every_file_belongs_to_a_cell():
     mixes = {f[:-5] for f in os.listdir(os.path.join(BENCH, "traffic"))}
     readers = {f[:-3] for f in os.listdir(os.path.join(BENCH, "metrics"))
                if f.endswith(".py")}
+    named = set()
+    for t in traffic:
+        with open(os.path.join(BENCH, "traffic", t + ".json")) as f:
+            named.add(json.load(f)["kind"])
     assert limits == cells and mixes == traffic
     assert readers == {m["name"] for m in s["per_layer"]}
+    assert set(spec.kinds(BENCH)) == named
 
 
 def test_tpch_sizes_follow_the_source():
