@@ -1,24 +1,17 @@
-"""``correct`` on CPU at a tiny size: the reference agrees with the
-program, the bfloat16 control fails the limits of the real cells, and a
-whole run with the timed path broken underneath reports ``correct`` false
-for each fault the cells can have; the same for a kind that only the
-tiny root holds, under its own limits."""
-import json
-import os
-
+"""``correct`` on CPU at a tiny size, over every fixture cell: the
+reference agrees with the program, and the bfloat16 control fails the
+cell's limits (a tiny copy of a real cell borrows the real cell's, a
+root-only kind has its own); a whole run with the timed path broken
+underneath reports ``correct`` false for each fault the cells can have."""
 import numpy as np
 import pytest
 
 from bench import drive, spec
 from bench.harness import run_cell
-from bench.tests.tiny import BENCH, make_root
+from bench.tests.tiny import CELLS, make_root
 
 SEED = 2**33 + 5
-KIND_OF = {"tiny-tpch.query": "tpch-z2-m512.query",
-           "tiny-tpch.topk": "tpch-z2-m512.query",
-           "tiny-tpch.ingest": "tpch-z2-m512.ingest"}
 ROOT_ONLY_CELL = "tiny-exact.dot"      # its kind's file is in the root only
-CELLS = sorted(KIND_OF) + [ROOT_ONLY_CELL]
 
 
 @pytest.fixture(scope="module")
@@ -26,36 +19,25 @@ def root(tmp_path_factory):
     return make_root(str(tmp_path_factory.mktemp("bench")))
 
 
-def limits(cell):
-    """The real cell's limits for a tiny copy of it; a root-only kind's
-    own."""
-    if cell.name not in KIND_OF:
-        return cell.limits
-    with open(os.path.join(BENCH, "limits", KIND_OF[cell.name] + ".json")) as f:
-        return json.load(f)
-
-
 def run(root, name, **kw):
     cell = spec.load_cell(root, name)
-    cell.limits = limits(cell)
     return run_cell(cell, SEED, 0.5, False, device_kind="TPU v5 lite", **kw)
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", sorted(CELLS))
 def test_sound_run_is_correct(root, name):
     r = run(root, name)
     assert r["correct"], r["checks"]
     assert r["attempted"] > 0 and r["failed"] == 0
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", sorted(CELLS))
 def test_control_fails(root, name):
     cell = spec.load_cell(root, name)
     kind = drive.traffic_kind(cell)(cell, SEED)
     kind.setup(2.0, program=False)
     numbers = kind.control_numbers()
-    lim = limits(cell)
-    assert any(numbers[k] > lim[k] for k in lim), numbers
+    assert any(numbers[k] > cell.limits[k] for k in cell.limits), numbers
 
 
 def _alter_query(monkeypatch):

@@ -1,6 +1,7 @@
 """Kinds of traffic are found by name: a cell's kind is the file its
-traffic names under the cell's benchmark directory, every kind keeps the
-contract of ``bench.kinds``, and a kind with no file is refused."""
+traffic names under the cell's benchmark directory, every kind has a CPU
+fixture cell (``bench/tests/fixtures/``) and keeps the contract of
+``bench.kinds`` there, and a kind with no file is refused."""
 import json
 import os
 import subprocess
@@ -20,10 +21,16 @@ def root(tmp_path_factory):
 
 
 def cell_of_kind(root, kind):
+    """The first fixture cell whose traffic is of ``kind``; none fails the
+    test, naming the files that would give the kind one."""
     for name in CELLS:
         cell = spec.load_cell(root, name)
         if cell.traffic["kind"] == kind:
             return cell
+    pytest.fail(f"kind {kind!r} has no CPU fixture: add "
+                f"bench/tests/fixtures/cells/<cell>.json naming a mix "
+                f"bench/tests/fixtures/traffic/<mix>.json of kind {kind!r} "
+                f"(and its configuration under bench/tests/fixtures/configs/)")
 
 
 @pytest.mark.parametrize("kind", spec.kinds(BENCH))
@@ -34,6 +41,12 @@ def test_every_kind_keeps_the_contract(root, kind):
     obj = cls(cell, 2**40 + 3)
     obj.setup(1.0, program=False)       # the control's inputs, no program
     assert set(obj.control_numbers()) == set(cell.limits)
+
+
+def test_a_kind_with_no_fixture_names_the_file_to_add(root):
+    with pytest.raises(pytest.fail.Exception,
+                       match="bench/tests/fixtures/cells/<cell>.json"):
+        cell_of_kind(root, "no_fixture_kind")
 
 
 def test_a_kind_in_the_root_only_is_found(root):
